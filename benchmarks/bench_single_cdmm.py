@@ -29,7 +29,6 @@ from repro.cdmm.api import (
     ProblemSpec,
 )
 from repro.cdmm.elastic import worker_closures
-from repro.compat import shard_map
 from repro.core import make_ring
 
 from .common import emit, timeit
@@ -153,20 +152,20 @@ def _bench_shard_map_stages(N, schemes, size, spec, A, B, iters):
             i = lax.axis_index("workers")
             return (_sch.encode_a_at(a, i)[None], _sch.encode_b_at(b, i)[None])
 
-        enc = shard_map(enc_body, mesh=mesh, in_specs=(rep, rep),
-                        out_specs=(shard, shard), check=False)
+        enc = jax.shard_map(enc_body, mesh=mesh, in_specs=(rep, rep),
+                            out_specs=(shard, shard), check_vma=False)
 
         def cmp_body(fa, gb, _sch=sch):
             return _sch.worker_compute(fa, gb)
 
-        cmp = shard_map(cmp_body, mesh=mesh, in_specs=(shard, shard),
-                        out_specs=shard, check=False)
+        cmp = jax.shard_map(cmp_body, mesh=mesh, in_specs=(shard, shard),
+                            out_specs=shard, check_vma=False)
 
         def gather_body(h):
             return lax.all_gather(h[0], "workers")
 
-        gather = shard_map(gather_body, mesh=mesh, in_specs=(shard,),
-                           out_specs=rep, check=False)
+        gather = jax.shard_map(gather_body, mesh=mesh, in_specs=(shard,),
+                               out_specs=rep, check_vma=False)
 
         FA, GB = jax.jit(enc)(A, B)
         H = sch.worker_compute(FA, GB)

@@ -30,8 +30,6 @@ live* workers (stable order), so repeated calls are bitwise-reproducible.
 subset per run under a randomized trace — and still returns the same bits,
 because the any-R decode is exact for every subset (that invariant is
 property-tested in tests/test_elastic.py).
-
-All shard_map calls route through the ``repro.compat`` shim.
 """
 from __future__ import annotations
 
@@ -43,9 +41,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.straggler import select_workers
-from repro.kernels import gr_matmul, kernel_auto_enabled, kernel_supported
+from repro.kernels import gr_matmul
 
 from .api import CdmmScheme
 from .planner import Plan
@@ -133,7 +130,7 @@ def shard_worker_body(
     B: jnp.ndarray,
     mask: jnp.ndarray,
     *,
-    use_kernel: Optional[bool] = None,
+    use_kernel: bool = False,
     key: Optional[jax.Array] = None,
 ) -> jnp.ndarray:
     """Per-shard master/worker protocol: call inside shard_map over ``axis``
@@ -141,20 +138,17 @@ def shard_worker_body(
 
     Each shard encodes only its own codeword pair (encode-at-worker: the
     broadcast-blocks upload model — no shard materialises all N shares),
-    computes the local block product (Pallas kernel when supported), then
-    all-gathers responses and decodes from the first R live workers.
-    ``use_kernel=None`` auto-enables the kernel whenever it would actually
-    compile for the scheme's ring (``kernel_auto_enabled``); True forces it
-    (interpret mode on CPU), False pins the XLA reference.
+    computes the local block product, then all-gathers responses and
+    decodes from the first R live workers.
+    ``use_kernel=True`` computes with the Pallas kernel (interpret mode on
+    CPU; a ring outside its envelope raises); False uses XLA.
     ``key`` (replicated) feeds every shard the SAME mask randomness, so the
     secure codeword polynomial is consistent across workers.
     """
-    if use_kernel is None:
-        use_kernel = kernel_auto_enabled(scheme.ring)
     i = lax.axis_index(axis)
     fa = scheme.encode_a_at(A, i, key=key)
     gb = scheme.encode_b_at(B, i, key=key)
-    if use_kernel and kernel_supported(scheme.ring):
+    if use_kernel:
         h = gr_matmul(fa, gb, scheme.ring)
     else:
         h = scheme.worker_compute(fa[None], gb[None])[0]
@@ -172,10 +166,9 @@ class ShardMapBackend:
         self,
         mesh: Optional[Mesh] = None,
         axis: str = "workers",
-        use_kernel: Optional[bool] = None,
+        use_kernel: bool = False,
     ):
-        # None = auto: tuned Pallas kernel wherever it compiles for the
-        # scheme's ring (see shard_worker_body)
+        # use_kernel=True: the Pallas kernel (see shard_worker_body)
         self.mesh, self.axis, self.use_kernel = mesh, axis, use_kernel
 
     def _mesh_for(self, N: int) -> Mesh:
@@ -203,7 +196,7 @@ class ShardMapBackend:
             mask = jnp.ones(scheme.N, dtype=bool)
         spec = P()  # CDMM redundancy is in the computation: operands replicated
         # the key rides in as a closure constant, replicated to every shard
-        f = shard_map(
+        f = jax.shard_map(
             lambda a, b, m: shard_worker_body(
                 scheme, self.axis, a, b, m,
                 use_kernel=self.use_kernel, key=key,
@@ -211,7 +204,7 @@ class ShardMapBackend:
             mesh=mesh,
             in_specs=(spec, spec, spec),
             out_specs=spec,
-            check=False,
+            check_vma=False,
         )
         return f(A, B, mask)
 

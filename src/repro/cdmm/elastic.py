@@ -43,7 +43,7 @@ import numpy as np
 
 from repro.core.galois import Ring
 from repro.core.straggler import WorkerTrace
-from repro.kernels import gr_matmul, kernel_auto_enabled, kernel_supported
+from repro.kernels import gr_matmul
 from repro.runtime.elastic import replan_batch
 
 from .api import CdmmScheme, ProblemSpec
@@ -108,7 +108,7 @@ def decode_responses(
 
 
 def worker_closures(
-    scheme: CdmmScheme, keyed: bool = False, use_kernel: Optional[bool] = None
+    scheme: CdmmScheme, keyed: bool = False, use_kernel: bool = False
 ):
     """Jitted (encode_at, compute) closures, cached per scheme instance so
     repeated elastic calls never re-trace.  The worker id is a traced scalar
@@ -116,13 +116,10 @@ def worker_closures(
     compute (single-use buffers; donation is a warn-only no-op on CPU).
     ``keyed`` selects the keyed-encode variant (the masked-randomness seam:
     the PRNG key is a traced argument so rekeying never re-compiles).
-    ``use_kernel`` (None = auto via ``kernel_auto_enabled``) routes each
-    worker's block product through the tuned Pallas kernel — every
-    registered scheme's ``worker_compute`` is exactly the ring matmul of
-    its two shares, so the substitution is scheme-agnostic and exact."""
-    if use_kernel is None:
-        use_kernel = kernel_auto_enabled(scheme.ring)
-    use_kernel = use_kernel and kernel_supported(scheme.ring)
+    ``use_kernel=True`` routes each worker's block product through the
+    tuned Pallas kernel (False: XLA) — every registered scheme's
+    ``worker_compute`` is exactly the ring matmul of its two shares, so the
+    substitution is scheme-agnostic and exact."""
     ops = scheme.__dict__.setdefault("_elastic_ops", {})
     ename = "encode_keyed" if keyed else "encode"
     if ename not in ops:
@@ -168,13 +165,12 @@ class ElasticBackend:
         trace: Optional[WorkerTrace] = None,
         max_threads: Optional[int] = None,
         simulate_ms_scale: float = 0.0,
-        use_kernel: Optional[bool] = None,
+        use_kernel: bool = False,
     ):
         self.trace = trace
         self.max_threads = max_threads
         self.simulate_ms_scale = simulate_ms_scale
-        # None = auto: workers use the tuned Pallas kernel wherever it
-        # compiles for the scheme's ring (see worker_closures)
+        # True: workers use the Pallas kernel (see worker_closures)
         self.use_kernel = use_kernel
         self.last_stats: Optional[ElasticStats] = None
         self._pool: Optional[ThreadPoolExecutor] = None

@@ -27,7 +27,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.batch_rmfe import BatchEPRMFE
 from repro.core.ep_codes import EPCode
 from repro.core.galois import Ring
@@ -142,12 +141,12 @@ def cdmm_shard_map(
     spec = P()  # replicated
 
     def mapped(*args):
-        return shard_map(
+        return jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=tuple(spec for _ in args),
             out_specs=spec,
-            check=False,
+            check_vma=False,
         )(*args)
 
     return mapped

@@ -15,10 +15,14 @@ fictions.  This package runs it for real:
     bits (raw vs. on-wire bytes are counted end to end);
   * :mod:`repro.dist.worker` — the worker-process entrypoint
     (``python -m repro.dist.worker --connect ...``): registers with a
-    capability handshake (device kind, ring-arithmetic envelope, wire
-    codecs, autotune cache coverage), computes jitted ``gr_matmul`` block
-    products, and accumulates chunked shares into partial products so
-    transfer and compute overlap;
+    capability handshake (platform, device kind and count, ring-arithmetic
+    envelope, wire codecs, autotune cache coverage), computes jitted
+    ``Ring.matmul`` block products spread over its local devices, and
+    accumulates chunked shares into partial products so transfer and
+    compute overlap;
+  * :mod:`repro.dist.chips` — where pool processes run on a TPU host: one
+    chip-holding worker per host, the master pinned to the CPU, and the
+    persistent compile cache;
   * :mod:`repro.dist.master` — the master: accepts workers, tracks
     heartbeats and membership (``core.straggler.MembershipEvents``),
     dispatches per-worker ``encode_*_at`` shares (pipelined in

@@ -167,7 +167,7 @@ class PoolConfig:
     heartbeat_s: float = 0.5
     heartbeat_timeout: float = 5.0
     request_timeout: Optional[float] = None
-    use_kernel: Optional[bool] = None
+    use_kernel: bool = False
     spawn_timeout: float = 120.0
     obs_http_port: Optional[int] = None
     hedge_factor: Optional[float] = None

@@ -34,10 +34,12 @@ import signal
 import subprocess
 import sys
 import time
+import warnings
 from typing import Dict, List, Optional
 
 from repro import settings
 
+from . import chips
 from .config import Endpoint, HostSpec, PoolConfig
 
 __all__ = [
@@ -59,13 +61,29 @@ def spawn_local_workers(
     """Spawn ``count`` worker OS processes dialing ``address``.
 
     The one place worker processes are forked — LocalPool and the
-    hostfile/env worker groups all come through here.
+    hostfile/env worker groups all come through here.  On a TPU host the
+    one-process-per-chip rule applies (:mod:`repro.dist.chips`): one
+    worker whatever ``count`` says, which holds every chip and cannot fall
+    back to the CPU, and :class:`~repro.dist.chips.ChipBusy` while this
+    process's chip worker is alive.
     """
     from .master import _worker_env
 
     env = _worker_env()
-    # REPRO_POOL_LOG=1 lets worker stderr through for debugging
+    # REPRO_POOL_LOG=1 lets worker stderr through for debugging; a chip
+    # worker's always comes through, so a TPU that fails to start is seen
     sink = None if settings.get_bool("pool_log") else subprocess.DEVNULL
+    on_chip = chips.chip_bound(env)
+    if on_chip:
+        chips.claim_chip_worker()
+        if count > 1:
+            warnings.warn(
+                f"{count} workers requested on a TPU host; starting one "
+                f"({chips.RULE})", RuntimeWarning, stacklevel=2,
+            )
+        count = chips.host_workers(count, env)
+        env["JAX_PLATFORMS"] = "tpu"
+        sink = None
     procs = []
     for i in range(count):
         procs.append(subprocess.Popen(
@@ -79,6 +97,8 @@ def spawn_local_workers(
             stdout=subprocess.DEVNULL,
             stderr=sink,
         ))
+        if on_chip:
+            chips.register_chip_worker(procs[-1])
     return procs
 
 
@@ -127,11 +147,22 @@ class HostPool:
             host = "0.0.0.0" if cfg.multi_host else "127.0.0.1"
             cfg = cfg.with_(endpoint=Endpoint.tcp(host, 0))
         self.config = cfg
+        env = _worker_env()
+        local = sum(1 for spec in cfg.hosts if spec.is_local)
+        if local > 1 and chips.chip_bound(env):
+            raise chips.ChipBusy(
+                f"{local} local host entries would each start a chip "
+                f"worker; {chips.RULE}"
+            )
+        # a local chip-bound host starts one worker whatever its slots say
+        expected = sum(
+            chips.host_workers(spec.slots, env) if spec.is_local
+            else spec.slots for spec in cfg.hosts
+        )
         self.master = Master(config=cfg)
         connect_addr = self._advertised_address()
         self.agents: List[subprocess.Popen] = []
         pending_remote: List[HostSpec] = []
-        env = _worker_env()
         sink = (None if settings.get_bool("pool_log")
                 else subprocess.DEVNULL)
         for idx, spec in enumerate(cfg.hosts):
@@ -170,7 +201,7 @@ class HostPool:
                 )
         try:
             self.master.wait_for_workers(
-                cfg.total_workers, timeout=cfg.spawn_timeout
+                expected, timeout=cfg.spawn_timeout
             )
         except TimeoutError:
             self.close()

@@ -67,6 +67,7 @@ from repro.obs.health import DISPATCH_THRESHOLD, HealthTracker
 from repro.obs.metrics import MetricsRegistry
 from repro.stats import StatsSnapshot
 
+from . import chips
 from .config import Endpoint, PoolConfig, warn_deprecated_once
 from .protocol import Channel, ProtocolError, listen, negotiate
 
@@ -128,6 +129,11 @@ class _WorkerHandle:
         # worker-published load figures (heartbeat piggyback)
         self.tasks_done = 0
         self.busy_us = 0.0
+        self.compiles = 0  # XLA compiles in the worker process
+        self.device_tasks: List[int] = []  # shares computed per local device
+        # shares computed per product implementation ("xla" / "pallas")
+        self.matmul_tasks: Dict[str, int] = {}
+        self.tasks_sent = 0  # task headers this master sent the worker
 
     def send(self, header: Dict, arrays=None,
              codec: Optional[str] = None) -> Tuple[int, int]:
@@ -172,6 +178,9 @@ class Master:
         use_kernel: Optional[bool] = None,
         config: Optional[PoolConfig] = None,
     ):
+        if chips.chip_bound():
+            # encode/decode run here; the chips belong to the one worker
+            chips.pin_host_to_cpu()
         cfg = config or PoolConfig()
         if heartbeat_timeout is not None:
             cfg = cfg.with_(heartbeat_timeout=heartbeat_timeout)
@@ -185,8 +194,8 @@ class Master:
         )
         self._listener, self.address = listen(listen_addr)
         self.heartbeat_timeout = cfg.heartbeat_timeout
-        # None = let each worker auto-select (kernel wherever it compiles on
-        # the worker's device); True/False force it pool-wide
+        # True forces the Pallas kernel pool-wide; otherwise workers
+        # compute with XLA's ring.matmul
         self.use_kernel = cfg.use_kernel
         self.transport = cfg.transport
         self.compression_level = cfg.compression_level
@@ -224,6 +233,8 @@ class Master:
             ("completed", "requests decoded successfully"),
             ("failed", "requests that raised"),
             ("redispatched", "shares re-shipped after a worker death"),
+            ("share_errors", "error replies: shares whose compute raised "
+                             "on a worker"),
             ("hedged", "shares speculatively re-shipped past the hedge "
                        "deadline"),
             ("hedge_wasted", "hedged shares whose extra reply lost the "
@@ -344,6 +355,13 @@ class Master:
                     self.health.record_heartbeat(handle.wid)
                     handle.tasks_done = int(header.get("tasks_done", 0))
                     handle.busy_us = float(header.get("busy_us", 0.0))
+                    handle.compiles = int(header.get("compiles", 0))
+                    handle.device_tasks = list(
+                        header.get("device_tasks", ())
+                    )
+                    handle.matmul_tasks = dict(
+                        header.get("matmul_tasks", {})
+                    )
                     self._account(heartbeats=1)
                 elif kind == "echo_reply":
                     with self._lock:
@@ -468,6 +486,23 @@ class Master:
     def worker_caps(self) -> Dict[int, Dict]:
         with self._lock:
             return {w: dict(h.caps) for w, h in self._workers.items()}
+
+    def worker_reports(self) -> Dict[int, Dict]:
+        """Per live worker: tasks sent to it, and the figures of its last
+        heartbeat (tasks done, compute wall, XLA compiles, shares computed
+        on each of its devices and by each product implementation)."""
+        with self._lock:
+            return {
+                w: {
+                    "tasks_sent": h.tasks_sent,
+                    "tasks_done": h.tasks_done,
+                    "busy_us": h.busy_us,
+                    "compiles": h.compiles,
+                    "device_tasks": list(h.device_tasks),
+                    "matmul_tasks": dict(h.matmul_tasks),
+                }
+                for w, h in self._workers.items() if h.alive
+            }
 
     def worker_codecs(self) -> Dict[int, str]:
         """Negotiated wire codec per live worker."""
@@ -657,11 +692,7 @@ class Master:
             # master synthesizes its compute span from wall_us instead
             if req.trace is not None and handle.caps.get("tracing"):
                 header["trace"] = req.trace.trace_id
-            # None = auto: each worker decides per its own device/ring
-            # (kernel_auto_enabled on the worker side)
-            header["use_kernel"] = (
-                "auto" if self.use_kernel is None else bool(self.use_kernel)
-            )
+            header["use_kernel"] = bool(self.use_kernel)
             delay = self.task_delay_ms.get(handle.wid, 0.0)
             if delay > 0.0:
                 header["delay_ms"] = delay
@@ -671,6 +702,8 @@ class Master:
                 req.pending[task] = (
                     i, fa, gb, handle.wid, time.perf_counter()
                 )
+            with self._lock:
+                handle.tasks_sent += 1
             try:
                 t_send = obs.now()
                 chunks = self._stream_chunks(fa, gb)
@@ -690,15 +723,23 @@ class Master:
                     header["stream"] = len(starts)
                     raw, wire = handle.send(header)
                     for seq, lo in enumerate(starts):
-                        hi = min(lo + step, r)
+                        cfa = fa[:, lo:lo + step, :]
+                        cgb = gb[lo:lo + step, :, :]
+                        short = step - cfa.shape[1]
+                        if short:
+                            # zero-pad the tail chunk (zero rows add zero
+                            # to the product): every chunk of a share has
+                            # one shape, so the worker compiles it once
+                            cfa = np.pad(cfa, ((0, 0), (0, short), (0, 0)))
+                            cgb = np.pad(cgb, ((0, short), (0, 0), (0, 0)))
                         craw, cwire = handle.send(
                             {
                                 "type": "chunk", "req": req.rid,
                                 "task": task, "seq": seq,
                             },
                             {
-                                "fa": np.ascontiguousarray(fa[:, lo:hi, :]),
-                                "gb": np.ascontiguousarray(gb[lo:hi, :, :]),
+                                "fa": np.ascontiguousarray(cfa),
+                                "gb": np.ascontiguousarray(cgb),
                             },
                         )
                         raw += craw
@@ -919,6 +960,7 @@ class Master:
                     # then write it off — the any-R decode only needs R of
                     # the remaining shares
                     bad_wid, err = payload
+                    self._account(share_errors=1)
                     errors[i] = errors.get(i, 0) + 1
                     healthy = [
                         s for s in shares
@@ -1102,17 +1144,38 @@ class LocalPool:
         # the launcher owns process spawning; LocalPool is its local case
         from .launch import spawn_local_workers
 
-        self.procs: List[subprocess.Popen] = spawn_local_workers(
-            self.master.address, cfg.workers,
-            heartbeat_s=cfg.heartbeat_s, name_prefix="local",
-        )
+        self.procs: List[subprocess.Popen] = []
         try:
-            self.master.wait_for_workers(
-                cfg.workers, timeout=cfg.spawn_timeout
+            self.procs = spawn_local_workers(
+                self.master.address, cfg.workers,
+                heartbeat_s=cfg.heartbeat_s, name_prefix="local",
             )
-        except TimeoutError:
+            # one on a TPU host, whatever cfg.workers says
+            self._wait_for_workers(len(self.procs), cfg.spawn_timeout)
+        except BaseException:
             self.close()
             raise
+
+    def _wait_for_workers(self, n: int, timeout: float) -> None:
+        """Wait for ``n`` hellos; a worker process that exits first (a
+        device that failed to start, a bad import) fails the wait at once
+        instead of at the timeout."""
+        deadline = time.time() + timeout
+        while True:
+            try:
+                self.master.wait_for_workers(
+                    n, timeout=max(0.0, min(0.5, deadline - time.time()))
+                )
+                return
+            except TimeoutError:
+                for proc in self.procs:
+                    if proc.poll() is not None:
+                        raise RuntimeError(
+                            f"worker pid {proc.pid} exited with code "
+                            f"{proc.returncode} before joining the pool"
+                        ) from None
+                if time.time() >= deadline:
+                    raise
 
     @property
     def address(self) -> str:
@@ -1156,7 +1219,9 @@ class LocalPool:
                 proc.wait(timeout=10)
             except subprocess.TimeoutExpired:  # pragma: no cover
                 proc.kill()
-                proc.wait(timeout=10)
+                # a worker holding four TPU chips took over 20 s to exit
+                # while its devices were released
+                proc.wait(timeout=120)
         if self._tmpdir:
             try:
                 sock = os.path.join(self._tmpdir, "pool.sock")

@@ -1,7 +1,8 @@
 """Worker-process entrypoint: ``python -m repro.dist.worker --connect ADDR``.
 
-On connect the worker sends a ``hello`` capability handshake — device kind
-(``jax.default_backend()``), pid, the ring-arithmetic envelope it can serve
+On connect the worker sends a ``hello`` capability handshake — its devices
+as JAX reports them (platform, ``device_kind``, count), pid, the
+ring-arithmetic envelope it can serve
 (the p=2 machine-word fast path plus the general small-modulus path), the
 wire codecs it can decode (``protocol.supported_codecs()`` — the master
 picks one per connection, so a v0 peer that advertises nothing simply gets
@@ -10,12 +11,15 @@ messages until the master says ``shutdown`` or the socket drops.
 
 A task carries the codeword-ring constructor args, a share index and the
 two encoded shares; the worker computes the block product ``h = fa @ gb``
-in that ring (jitted once per ring; routed through the tuned Pallas
-``gr_matmul`` kernel when the master asks for it and the ring is inside the
-kernel envelope) and replies with the result encoded in the connection's
-codec.  Workers never see the operands A and B, only their own shares —
-exactly the paper's upload model, and what makes the T-private schemes
-private against the pool.
+in that ring with XLA's ``Ring.matmul`` (jitted once per ring; the Pallas
+``gr_matmul`` kernel only when the master forces it, and then its errors
+are the task's errors) and replies with the result encoded in the
+connection's codec.  Share ``i`` is computed on local accelerator device
+``i mod device_count``, so one worker process spreads its shares over every
+chip of its host (a CPU worker computes on its first device).  Workers
+never see the operands A and B, only their own shares — exactly the
+paper's upload model, and what makes the T-private schemes private
+against the pool.
 
 Pipelined streaming: a task header with ``stream: k`` carries no arrays;
 ``k`` ``chunk`` messages follow (interleavable with other tasks — chunks
@@ -48,6 +52,7 @@ import numpy as np
 
 from repro.obs.trace import now as obs_now
 
+from . import chips
 from .protocol import (
     PROTOCOL_VERSION,
     ProtocolError,
@@ -60,16 +65,16 @@ from .protocol import (
 __all__ = ["WorkerRuntime", "main"]
 
 
-def _capabilities() -> Dict:
-    """The capability handshake payload (device, rings, codecs, autotune)."""
+def _capabilities(devices: list) -> Dict:
+    """The capability handshake payload (devices, rings, codecs, autotune)."""
     import jax
 
     from repro.kernels.autotune import load_cache
 
-    device = jax.default_backend()
+    platform = devices[0].platform
     try:
         cache = load_cache()
-        prefix = f"{device}|"
+        prefix = f"{platform}|"
         coverage = sum(1 for k in cache if k.startswith(prefix))
         entries = len(cache)
     except Exception:  # a corrupt cache must not keep a worker out of the pool
@@ -77,7 +82,10 @@ def _capabilities() -> Dict:
     return {
         "protocol": PROTOCOL_VERSION,
         "pid": os.getpid(),
-        "device": device,
+        # the devices as JAX reports them
+        "platform": platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
         "jax_version": jax.__version__,
         # ring envelope mirrors Ring.__init__'s overflow discipline
         "rings": {"p2_max_e": 32, "general_max_q": 1 << 12},
@@ -119,8 +127,14 @@ class WorkerRuntime:
         self.heartbeat_s = heartbeat_s
         self._send_lock = threading.Lock()
         self._stop = threading.Event()
-        # (p, e, degrees, use_kernel) -> (ring, jitted product, jitted add)
+        # (p, e, degrees, use_kernel)
+        #     -> (ring, jitted product, jitted add, product implementation)
         self._compute: Dict[Tuple, Tuple] = {}
+        self._devices: list = []  # jax.local_devices(), set in serve()
+        self.device_tasks: list = []  # shares computed per local device
+        # shares computed per product implementation ("xla" / "pallas")
+        self.matmul_tasks: Dict[str, int] = {}
+        self._compiles = lambda: 0  # running XLA compile count
         # (req, task) -> _StreamState for chunked tasks
         self._streams: Dict[Tuple[int, int], _StreamState] = {}
         self.tasks_done = 0
@@ -128,33 +142,27 @@ class WorkerRuntime:
 
     # -- ring-matmul closures (jitted once per ring) -----------------------
 
-    def _closure(self, p: int, e: int, degrees: Tuple[int, ...], use_kernel):
+    def _closure(self, p: int, e: int, degrees: Tuple[int, ...],
+                 use_kernel: bool):
         import jax
 
         from repro.core.galois import make_ring
-        from repro.kernels import (
-            gr_matmul,
-            kernel_auto_enabled,
-            kernel_supported,
-        )
+        from repro.kernels import gr_matmul
 
         key = (p, e, degrees, use_kernel)
         if key not in self._compute:
             ring = make_ring(p, e, degrees)
-            # "auto" = kernel wherever it compiles on THIS device (the
-            # worker decides; the master doesn't know worker hardware)
-            use = (
-                kernel_auto_enabled(ring)
-                if use_kernel == "auto" else bool(use_kernel)
-            )
-            if use and kernel_supported(ring):
-                fn = jax.jit(lambda fa, gb: gr_matmul(fa, gb, ring))
+            if use_kernel:
+                # forced by the master: a ring outside the kernel envelope,
+                # or a chip compiler that refuses the kernel, fails the task
+                fn, impl = jax.jit(
+                    lambda fa, gb: gr_matmul(fa, gb, ring)), "pallas"
             else:
-                fn = jax.jit(ring.matmul)
+                fn, impl = jax.jit(ring.matmul), "xla"
             # chunk accumulation: partial products are already reduced, so
             # ring addition combines them exactly
             add = jax.jit(ring.add)
-            self._compute[key] = (ring, fn, add)
+            self._compute[key] = (ring, fn, add, impl)
         return self._compute[key]
 
     # -- messaging ---------------------------------------------------------
@@ -166,11 +174,34 @@ class WorkerRuntime:
     def _heartbeat_loop(self) -> None:
         while not self._stop.wait(self.heartbeat_s):
             try:
-                self._send({"type": "heartbeat", "t": time.time(),
-                            "tasks_done": self.tasks_done,
-                            "busy_us": round(self.busy_us, 1)})
+                self._send(self._heartbeat())
             except OSError:
                 return  # master gone; the main loop notices on recv
+
+    def _heartbeat(self) -> Dict:
+        return {"type": "heartbeat", "t": time.time(),
+                "tasks_done": self.tasks_done,
+                "busy_us": round(self.busy_us, 1),
+                "compiles": self._compiles(),
+                "device_tasks": list(self.device_tasks),
+                "matmul_tasks": dict(self.matmul_tasks)}
+
+    def _place(self, header: Dict, arrays: Dict):
+        """The task's two operands, on the local device of its share."""
+        import jax
+
+        dev = self._devices[int(header["i"]) % len(self._devices)]
+        return jax.device_put(arrays["fa"], dev), jax.device_put(
+            arrays["gb"], dev
+        )
+
+    def _task_closure(self, header: Dict):
+        ring = header["ring"]
+        return self._closure(
+            int(ring["p"]), int(ring["e"]),
+            tuple(int(d) for d in ring["degrees"]),
+            header.get("use_kernel") is True,
+        )
 
     def _reply(self, header: Dict, ok: bool, h=None, err: str = "",
                wall_us: float = 0.0, t0: Optional[float] = None,
@@ -205,6 +236,10 @@ class WorkerRuntime:
         self._send(reply, out, codec=header.get("codec", "raw"))
         self.tasks_done += 1
         self.busy_us += wall_us
+        if ok:
+            self.device_tasks[int(header["i"]) % len(self._devices)] += 1
+            impl = self._task_closure(header)[3]
+            self.matmul_tasks[impl] = self.matmul_tasks.get(impl, 0) + 1
 
     def _apply_injection(self, header: Dict) -> None:
         delay_ms = float(header.get("delay_ms", 0.0))
@@ -235,13 +270,9 @@ class WorkerRuntime:
         t0 = time.perf_counter()
         try:
             self._apply_injection(header)
-            _, fn, _ = self._closure(
-                int(header["ring"]["p"]),
-                int(header["ring"]["e"]),
-                tuple(int(d) for d in header["ring"]["degrees"]),
-                header.get("use_kernel", "auto"),
-            )
-            h = fn(arrays["fa"], arrays["gb"])
+            _, fn, _, _ = self._task_closure(header)
+            fa, gb = self._place(header, arrays)
+            h = fn(fa, gb)
         except Exception as e:  # computation errors surface at the master
             self._reply(header, ok=False, err=f"{type(e).__name__}: {e}",
                         wall_us=(time.perf_counter() - t0) * 1e6, t0=tw0)
@@ -259,13 +290,9 @@ class WorkerRuntime:
         if not state.failed:
             t0 = time.perf_counter()
             try:
-                _, fn, add = self._closure(
-                    int(state.header["ring"]["p"]),
-                    int(state.header["ring"]["e"]),
-                    tuple(int(d) for d in state.header["ring"]["degrees"]),
-                    state.header.get("use_kernel", "auto"),
-                )
-                part = fn(arrays["fa"], arrays["gb"])
+                _, fn, add, _ = self._task_closure(state.header)
+                fa, gb = self._place(state.header, arrays)
+                part = fn(fa, gb)
                 state.h = part if state.h is None else add(state.h, part)
             except Exception as e:
                 state.failed = True
@@ -284,7 +311,17 @@ class WorkerRuntime:
                             streamed=int(state.header.get("stream", 0)))
 
     def serve(self) -> int:
-        self._send({"type": "hello", "name": self.name, **_capabilities()})
+        import jax
+
+        self._compiles = chips.compile_counter()
+        devices = jax.local_devices()
+        # shares spread over accelerator devices; a CPU's devices are views
+        # of one host's cores, where spreading buys nothing and costs one
+        # compile per device
+        self._devices = devices[:1] if devices[0].platform == "cpu" else devices
+        self.device_tasks = [0] * len(self._devices)
+        self._send({"type": "hello", "name": self.name,
+                    **_capabilities(devices)})
         hb = threading.Thread(target=self._heartbeat_loop, daemon=True)
         hb.start()
         try:
@@ -305,9 +342,7 @@ class WorkerRuntime:
                                 "seq": header.get("seq")}, arrays,
                                codec=header.get("codec", "raw"))
                 elif kind == "ping":
-                    self._send({"type": "heartbeat", "t": time.time(),
-                                "tasks_done": self.tasks_done,
-                                "busy_us": round(self.busy_us, 1)})
+                    self._send(self._heartbeat())
                 elif kind == "shutdown":
                     return 0
                 # unknown types are ignored: forward-compatible masters
@@ -334,6 +369,11 @@ def main(argv: Optional[list] = None) -> int:
         "--connect-timeout", type=float, default=30.0, metavar="SECONDS",
     )
     args = ap.parse_args(argv)
+    if chips.chip_bound():
+        # chip compiles take tens of seconds; CPU pools skip the cache (many
+        # CPU workers compile the same programs at once, and JAX's cache
+        # writes are not atomic)
+        chips.enable_compile_cache()
     sock = connect(args.connect, timeout=args.connect_timeout)
     return WorkerRuntime(sock, args.name, args.heartbeat).serve()
 

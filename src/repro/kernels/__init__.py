@@ -9,7 +9,6 @@ from .autotune import cached_blocks, candidate_blocks, tune_key
 from .ops import (
     coded_encode,
     gr_matmul,
-    kernel_auto_enabled,
     kernel_supported,
     pick_blocks,
 )
@@ -19,7 +18,6 @@ __all__ = [
     "gr_matmul",
     "coded_encode",
     "kernel_supported",
-    "kernel_auto_enabled",
     "pick_blocks",
     "gr_matmul_ref",
     "gr_matmul_planar_ref",

@@ -27,7 +27,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
 from repro.core.galois import Ring
 
 MAX_D = 16  # unrolled D^2 dots per block; beyond this use the jnp reference
@@ -129,7 +128,7 @@ def gr_matmul_planar(
         out_shape=jax.ShapeDtypeStruct((D, Tp, Sp), jnp.uint32),
         scratch_shapes=[pltpu.VMEM((ring.K, bt, bs), jnp.uint32)],
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(A, B)

@@ -1,9 +1,15 @@
 """Jit-ready wrappers around the Pallas Galois-ring matmul kernel.
 
 Handles layout conversion (interleaved (t, r, D) <-> planar (D, t, r)),
-padding to block multiples, block-size selection (autotuned cache first,
-static heuristic as fallback), and fallback to the jnp reference when the
-ring is outside the kernel envelope (odd p or D > MAX_D).
+padding to block multiples and block-size selection (autotuned cache first,
+static heuristic as fallback).  A ring outside the kernel envelope (odd p,
+e > 32 or D > MAX_D) is an error: callers that want the ring product for any
+ring call ``Ring.matmul``.
+
+The kernel is never chosen by backend name.  It does not compile for the
+TPU v5e today (Mosaic refuses both its scatter and its 32-bit integer dot),
+so every production path computes with XLA's ``Ring.matmul`` unless a caller
+forces the kernel, and then the compiler's error is the caller's error.
 """
 from __future__ import annotations
 
@@ -16,7 +22,6 @@ from repro.core.galois import Ring
 
 from .autotune import cached_blocks
 from .gr_matmul import MAX_D, _round_up, gr_matmul_planar
-from .ref import gr_matmul_ref
 
 
 def pick_blocks(t: int, r: int, s: int) -> Tuple[int, int, int]:
@@ -33,19 +38,6 @@ def kernel_supported(ring: Ring) -> bool:
     return ring.p == 2 and ring.e <= 32 and ring.D <= MAX_D
 
 
-def kernel_auto_enabled(ring: Ring) -> bool:
-    """Should a backend default its workers onto the kernel path?
-
-    True when the ring is inside the kernel envelope AND the kernel
-    actually compiles — i.e. on TPU, the only Pallas target this kernel
-    lowers for (VMEM scratch + Mosaic compiler params).  On CPU it would
-    run in interpret mode (a validation path, not a perf path) and on GPU
-    it would fail to lower, so both default to the XLA reference unless
-    explicitly forced.
-    """
-    return kernel_supported(ring) and jax.default_backend() == "tpu"
-
-
 def gr_matmul(
     A: jnp.ndarray,
     B: jnp.ndarray,
@@ -53,18 +45,20 @@ def gr_matmul(
     *,
     blocks: Optional[Tuple[int, int, int]] = None,
     interpret: Optional[bool] = None,
-    force_ref: bool = False,
 ) -> jnp.ndarray:
     """Ring matmul (t, r, D) x (r, s, D) -> (t, s, D) via the Pallas kernel.
 
-    On CPU containers ``interpret`` defaults to True (kernel body runs in
-    python for validation); on TPU it compiles to Mosaic.
+    On the CPU ``interpret`` defaults to True (kernel body runs in python
+    for validation); on an accelerator it compiles to Mosaic or raises.
     """
     t, r, D = A.shape
     r2, s, D2 = B.shape
     assert r == r2 and D == D2 == ring.D
-    if force_ref or not kernel_supported(ring):
-        return gr_matmul_ref(A, B, ring)
+    if not kernel_supported(ring):
+        raise ValueError(
+            f"GR({ring.p}^{ring.e},{ring.D}) is outside the kernel envelope "
+            f"(p=2, e<=32, D<={MAX_D}); use Ring.matmul"
+        )
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     if blocks is None:

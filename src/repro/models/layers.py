@@ -15,7 +15,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.runtime.sharding import ParamSpec, shard
 
@@ -425,12 +424,12 @@ def _apply_moe_ep_a2a(
     else:
         shared = ()
         sspec = ()
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(bspec, P(None, None), espec, espec, espec) + sspec,
         out_specs=(bspec, P()),
-        check=False,
+        check_vma=False,
     )(x, p["router"].astype(jnp.float32), p["w_gate"], p["w_in"], p["w_out"], *shared)
     return out, aux
 
@@ -527,12 +526,12 @@ def _apply_moe_shardmap(
     else:
         shared = ()
         sspec = ()
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         lambda xl, router, wg, wi, wo, *sh: body(xl, router, wg, wi, wo, sh or None),
         mesh=mesh,
         in_specs=(bspec, P(None, None), espec, espec, espec) + sspec,
         out_specs=(bspec, P()),
-        check=False,
+        check_vma=False,
     )(x, p["router"].astype(jnp.float32), p["w_gate"], p["w_in"], p["w_out"], *shared)
     return out, aux
 

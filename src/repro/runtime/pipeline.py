@@ -18,7 +18,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 def pipeline_body(
@@ -81,10 +80,10 @@ def pipelined_apply(
     """shard_map wrapper: layer-stack leading dim sharded over ``axis``."""
     pspec = jax.tree.map(lambda _: P(axis), params_stacked)
     body = partial(pipeline_body, stage_fn, axis=axis, microbatches=microbatches)
-    return shard_map(
+    return jax.shard_map(
         lambda p, xx: body(p, xx),
         mesh=mesh,
         in_specs=(pspec, P()),
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )(params_stacked, x)

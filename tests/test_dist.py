@@ -168,12 +168,52 @@ def _problem(scheme, rng):
 
 
 def test_capability_handshake(pool):
+    ndev = len(jax.local_devices())  # 8 (XLA_FLAGS above), as workers see
     caps = pool.master.worker_caps()
     assert len(caps) >= 1
     for c in caps.values():
-        assert c["device"] in ("cpu", "gpu", "tpu")
+        # the devices as the worker's JAX reports them: the test suite runs
+        # on the CPU, and workers inherit this process's XLA_FLAGS
+        assert c["platform"] == "cpu"
+        assert c["device_kind"] == "cpu"
+        assert c["device_count"] == ndev
         assert c["rings"]["p2_max_e"] == 32
         assert "entries" in c["autotune"]
+    # a CPU worker computes every share on its first device (shares spread
+    # over accelerator devices only), and reports what it computed
+    spec = ProblemSpec(t=8, r=8, s=8, n=1, ring=Z32, N=8, straggler_budget=2)
+    scheme = plan(spec).instantiate()
+    A, B = _problem(scheme, np.random.default_rng(11))
+    pool.execute(scheme, A, B, timeout=120)
+    deadline = time.time() + 30
+    while True:
+        reports = pool.master.worker_reports()
+        if all(r["tasks_done"] == r["tasks_sent"] for r in reports.values()):
+            break
+        assert time.time() < deadline, reports
+        time.sleep(0.05)
+    assert [len(r["device_tasks"]) for r in reports.values()] == [1] * len(
+        reports)
+    assert sum(r["device_tasks"][0] for r in reports.values()) >= scheme.N
+    # ... and by which implementation: XLA's Ring.matmul for every share
+    for r in reports.values():
+        assert r["matmul_tasks"] == (
+            {"xla": r["device_tasks"][0]} if r["device_tasks"][0] else {})
+
+
+def test_worker_spreads_shares_over_its_devices():
+    """On an accelerator host the one worker computes share i on local
+    device i mod count; this module's 8 virtual CPU devices stand in for
+    the chips (in-process, no socket traffic)."""
+    from repro.dist.worker import WorkerRuntime
+
+    rt = WorkerRuntime(sock=None)
+    rt._devices = jax.local_devices()
+    x = np.arange(4, dtype=np.uint32).reshape(2, 2)
+    for i in range(2 * len(rt._devices)):
+        fa, gb = rt._place({"i": i}, {"fa": x, "gb": x})
+        want = rt._devices[i % len(rt._devices)]
+        assert fa.devices() == gb.devices() == {want}
 
 
 def test_pool_matches_local_and_multiplexes_shares(pool):

@@ -10,7 +10,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro.compat import shard_map  # noqa: E402
 from repro.launch.hlo_analysis import _shape_bytes, collective_bytes  # noqa: E402
 
 NDEV = len(jax.devices())
@@ -32,7 +31,7 @@ def test_collectives_simple_psum():
         return jax.lax.psum(a, "x")
 
     m = jax.jit(
-        shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P(), check=False)
+        jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P(), check_vma=False)
     )
     text = m.lower(jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile().as_text()
     coll = collective_bytes(text)
@@ -55,7 +54,7 @@ def test_collectives_inside_scan_multiplied():
         return out
 
     m = jax.jit(
-        shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P(), check=False)
+        jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P(), check_vma=False)
     )
     text = m.lower(jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile().as_text()
     coll = collective_bytes(text)
@@ -73,7 +72,7 @@ def test_collectives_inside_scan_multiplied():
         return out
 
     m2 = jax.jit(
-        shard_map(g, mesh=mesh, in_specs=P("x"), out_specs=P(), check=False)
+        jax.shard_map(g, mesh=mesh, in_specs=P("x"), out_specs=P(), check_vma=False)
     )
     text2 = m2.lower(jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile().as_text()
     coll2 = collective_bytes(text2)

@@ -65,14 +65,14 @@ def test_kernel_block_sweep(rng):
 
 
 def test_kernel_fallback_odd_p(rng):
+    # outside the envelope there is no silent fallback to the reference:
+    # the caller is told to use Ring.matmul
     ring = make_ring(3, 2, (2,))
     assert not kernel_supported(ring)
     A = ring.random(rng, (4, 4))
     B = ring.random(rng, (4, 4))
-    out = gr_matmul(A, B, ring)  # silently uses the reference
-    np.testing.assert_array_equal(
-        np.asarray(out), np.asarray(gr_matmul_ref(A, B, ring))
-    )
+    with pytest.raises(ValueError, match="outside the kernel envelope"):
+        gr_matmul(A, B, ring)
 
 
 def test_kernel_jit(rng):

@@ -12,7 +12,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.checkpoint import Checkpointer  # noqa: E402
-from repro.compat import shard_map  # noqa: E402
 from repro.configs import ARCHS, smoke_shape  # noqa: E402
 from repro.data import DataConfig, TokenPipeline  # noqa: E402
 from repro.optim import (  # noqa: E402
@@ -109,8 +108,8 @@ def test_compressed_psum():
         return mean[None]
 
     out = jax.jit(
-        shard_map(body, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
-                      check=False)
+        jax.shard_map(body, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
+                          check_vma=False)
     )(gs)
     expect = np.mean(np.asarray(gs), axis=0)
     np.testing.assert_allclose(np.asarray(out)[0], expect, atol=2e-2)
