@@ -28,9 +28,8 @@ Two hedging rows measure the speculative re-dispatch plane:
                      ``speedup`` carry the margin, and both decodes are
                      asserted bit-identical to the local sync backend.
 
-Warmup matters more here than in the jit benches: the any-R ``decode_op``
-compiles per live *subset* (up to C(N, R) distinct decoders), so the first
-stream of each mode is a compile storm.  Each mode runs ``WARM_STREAMS``
+Warmup matters more here than in the jit benches: the first stream of each
+mode compiles the encode, worker and decode programs of every scheme.  Each mode runs ``WARM_STREAMS``
 full streams to reach the steady state the row claims to measure, then
 takes the median of ``iters`` measured streams.
 """

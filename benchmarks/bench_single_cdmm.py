@@ -89,7 +89,7 @@ def bench_one(N: int, uvw, sizes, iters: int = 3):
 def _bench_elastic_stages(N, schemes, size, spec, A, B, iters):
     """Stage rows through the elastic master's actual code path: the serial
     per-worker ``encode_*_at`` dispatch loop, one threaded worker's jitted
-    compute closure, the LRU-cached per-subset ``decode_op``, and the
+    compute closure, the any-R ``decode_op``, and the
     in-process response handoff (queue put/get of a share stack) — so
     ``repro.cdmm.calibrate`` fits the elastic backend its own coefficients
     instead of falling back to "local"."""

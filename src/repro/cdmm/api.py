@@ -36,8 +36,8 @@ executable adapter for the chosen partition.
 """
 from __future__ import annotations
 
-from collections import OrderedDict
 from dataclasses import dataclass
+from functools import partial
 from math import ceil, gcd, log
 from typing import (
     Callable,
@@ -51,6 +51,7 @@ from typing import (
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.batch_rmfe import BatchEPRMFE
 from repro.core.ep_codes import (
@@ -179,9 +180,9 @@ class CdmmScheme(Protocol):
 
     def decode(self, H: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray: ...
 
-    # per-subset decode operator: a jitted closure specialized to one live
-    # set, LRU-cached by index tuple — the elastic backend fires it the
-    # moment the R-th response lands (no per-call retrace/re-lowering)
+    # decode operator for one live set: the scheme's jitted decode with the
+    # indices bound as data — the elastic backend fires it the moment the
+    # R-th response lands (one compile per scheme, none per live set)
     def decode_op(self, idx: Tuple[int, ...]) -> Callable[[jnp.ndarray], jnp.ndarray]: ...
 
     def costs(self, spec: ProblemSpec) -> EPCosts: ...
@@ -190,14 +191,14 @@ class CdmmScheme(Protocol):
 class DecodeOpsMixin:
     """Shared ``decode_op`` implementation for every scheme adapter.
 
-    ``decode_op((3, 5, 6))`` returns a jitted decoder for exactly that live
-    set: ``dec(H_subset) -> C`` where ``H_subset`` stacks the responses of
-    workers 3, 5, 6 in that order.  Operators are LRU-cached per scheme
-    instance (key = the live-index tuple) so an elastic master that sees the
-    same membership pattern twice pays the Vandermonde-solve trace once.
+    ``decode_op((3, 5, 6))`` returns a decoder for exactly that live set:
+    ``dec(H_subset) -> C`` where ``H_subset`` stacks the responses of
+    workers 3, 5, 6 in that order.  The live indices enter the one jitted
+    ``decode`` of the scheme instance as traced data, so every live set of
+    a scheme runs the same compiled program: a new membership pattern costs
+    no trace and no compile.
     """
 
-    DECODE_OP_CACHE_SIZE = 64
     privacy_t = 0  # non-secure default; secure adapters override
 
     def decode_op(self, idx: Tuple[int, ...]) -> Callable[[jnp.ndarray], jnp.ndarray]:
@@ -209,15 +210,10 @@ class DecodeOpsMixin:
             )
         if len(set(idx)) != len(idx) or not all(0 <= i < self.N for i in idx):
             raise ValueError(f"{self.name}: invalid live set {idx} for N={self.N}")
-        cache = self.__dict__.setdefault("_decode_ops", OrderedDict())
-        op = cache.pop(idx, None)
-        if op is None:
-            iarr = jnp.asarray(idx, dtype=jnp.int32)
-            op = jax.jit(lambda H: self.decode(H, iarr))
-            while len(cache) >= self.DECODE_OP_CACHE_SIZE:
-                cache.popitem(last=False)
-        cache[idx] = op  # re-insert = mark most-recently-used
-        return op
+        decode = self.__dict__.get("_decode_jit")
+        if decode is None:
+            decode = self.__dict__["_decode_jit"] = jax.jit(self.decode)
+        return partial(decode, idx=np.asarray(idx, dtype=np.int32))
 
 
 # ---------------------------------------------------------------------------

@@ -194,19 +194,24 @@ class ShardMapBackend:
         mesh = self._mesh_for(scheme.N)
         if mask is None:
             mask = jnp.ones(scheme.N, dtype=bool)
-        spec = P()  # CDMM redundancy is in the computation: operands replicated
-        # the key rides in as a closure constant, replicated to every shard
-        f = jax.shard_map(
-            lambda a, b, m: shard_worker_body(
-                scheme, self.axis, a, b, m,
-                use_kernel=self.use_kernel, key=key,
-            ),
-            mesh=mesh,
-            in_specs=(spec, spec, spec),
-            out_specs=spec,
-            check_vma=False,
-        )
-        return f(A, B, mask)
+        # one jitted program per (scheme, mesh, axis, kernel choice): an
+        # eager shard_map would compile every primitive of the body anew on
+        # each call.  The key rides in replicated to every shard.
+        ops = scheme.__dict__.setdefault("_shard_map_ops", {})
+        op_key = (mesh, self.axis, self.use_kernel)
+        if op_key not in ops:
+            spec = P()  # CDMM redundancy is in the computation: replicated
+            use_kernel, axis = self.use_kernel, self.axis
+            ops[op_key] = jax.jit(jax.shard_map(
+                lambda a, b, m, k: shard_worker_body(
+                    scheme, axis, a, b, m, use_kernel=use_kernel, key=k,
+                ),
+                mesh=mesh,
+                in_specs=(spec, spec, spec, spec),
+                out_specs=spec,
+                check_vma=False,
+            ))
+        return ops[op_key](A, B, mask, key)
 
 
 _BACKENDS: dict = {

@@ -10,8 +10,8 @@ repo's first execution path whose completion time depends on R rather than N
     each worker's compute to a thread pool the moment that worker is
     scheduled, so later encodes overlap earlier computes;
   * worker results land on a response queue; the any-R decode fires the
-    moment the R-th response arrives, through a per-subset decode operator
-    (jitted once per live set, LRU-cached on the scheme — see
+    moment the R-th response arrives, through the scheme's decode operator
+    (jitted once per scheme, the live set passed as data — see
     ``CdmmScheme.decode_op``);
   * membership is a :class:`~repro.core.straggler.WorkerTrace`: workers may
     join late, leave mid-batch (never responding) or run slow; the master
@@ -93,7 +93,7 @@ def decode_responses(
     ``got`` maps worker index -> response for (at least) R workers.  The
     live set is canonicalized to sorted order — the any-R decode is
     subset-order agnostic as long as rows match ``idx``, and a canonical
-    order maximizes ``decode_op`` cache reuse across membership patterns.
+    order makes the same responders decode the same way every time.
     Both the in-process elastic master and the multi-process pool master
     (``repro.dist.master``) decode through here, so they are bit-identical
     by construction.

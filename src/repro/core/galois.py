@@ -497,6 +497,12 @@ class Ring:
             return self._modq(jnp.uint32(0) - a)
         return (jnp.uint32(self.q) - a) % jnp.uint32(self.q)
 
+    # ``mul`` and ``matmul`` are jitted with the ring as a static argument
+    # (rings hash by (p, e, degrees)): an eager call compiles once per
+    # (ring, shapes, dtypes) instead of re-tracing its loop body every time,
+    # and a call under an outer jit is inlined into the caller's program.
+
+    @partial(jax.jit, static_argnums=0)
     def mul(self, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
         """Elementwise ring product; a, b broadcastable with trailing dim D."""
         a, b = jnp.broadcast_arrays(a, b)
@@ -518,6 +524,7 @@ class Ring:
         out = self._chunk_dot(flat, self.FOLDJ)
         return self._modq(out.reshape(batch + (D,)))
 
+    @partial(jax.jit, static_argnums=0)
     def matmul(self, A: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
         """Ring matmul: (t, r, D) x (r, s, D) -> (t, s, D)."""
         t, r, D = A.shape

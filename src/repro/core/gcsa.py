@@ -89,9 +89,9 @@ def gr_solve(
     the check degrades to an accumulated flag, raised from a
     ``jax.debug.callback`` at run time under ``REPRO_DEBUG_SOLVE=1`` (off
     by default: the callback has per-call cost).  The jitted ``decode_op``
-    seam is instead covered by the duplicate-live-set check in
-    ``CSACode.decode`` / ``GCSACode.decode``, which inspects the concrete
-    ``idx`` closure before tracing touches it.
+    seam, whose live set is traced data, is instead covered by
+    ``decode_op``'s host-side check of the index tuple: duplicates raise
+    before the decode runs.
     """
     n = M.shape[0]
     ok = None
@@ -129,11 +129,11 @@ def gr_solve(
 
 def _check_live_set(idx) -> None:
     """Host-side decode guard: duplicate worker indices make the decode
-    system singular (repeated Cauchy-Vandermonde rows).  ``idx`` is concrete
-    even inside the jitted ``decode_op`` seam (the live set is closed over
-    as a constant), so this raises before tracing hides the pivot masks;
-    fully dynamic (traced) live sets fall through to ``gr_solve``'s
-    ``REPRO_DEBUG_SOLVE`` run-time guard."""
+    system singular (repeated Cauchy-Vandermonde rows).  A concrete ``idx``
+    (an eager decode, or one closed over as a constant under jit) raises
+    here before tracing hides the pivot masks; traced live sets (the
+    ``decode_op`` seam, which checks its index tuple itself) fall through
+    to ``gr_solve``'s ``REPRO_DEBUG_SOLVE`` run-time guard."""
     if isinstance(idx, jax.core.Tracer):
         return
     ii = np.asarray(idx).ravel()
@@ -236,9 +236,8 @@ class CSACode:
         """H (R, t, s, D) from workers idx (R,) -> (L, t, s, D) products.
 
         Guarded against silent garbage: duplicate live-set indices raise
-        host-side whenever ``idx`` is concrete (including the jitted
-        ``decode_op`` seam, whose live set is a static closure constant),
-        and ``gr_solve`` raises on any singular system when called eagerly.
+        host-side whenever ``idx`` is concrete, and ``gr_solve`` raises on
+        any singular system when called eagerly.
         """
         _check_live_set(idx)
         ring = self.ring

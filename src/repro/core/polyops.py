@@ -12,8 +12,9 @@ blocks >> N this is strictly MXU-friendlier (see DESIGN.md §3.2).
 """
 from __future__ import annotations
 
-from typing import Tuple
+from functools import partial
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax, vmap
@@ -81,9 +82,13 @@ def as_u32(obj_arr: np.ndarray) -> np.ndarray:
 
 # ---------------------------------------------------------------------------
 # jnp traceable versions (runtime point sets)
+#
+# Jitted with the ring (and sizes) static, so an eager call compiles its scans
+# once per (ring, shapes) rather than on every call.
 # ---------------------------------------------------------------------------
 
 
+@partial(jax.jit, static_argnums=(0, 2))
 def vandermonde(ring: Ring, points: jnp.ndarray, K: int) -> jnp.ndarray:
     """V[i, k] = points[i]^k, shape (n, K, D); traceable scan over K."""
     n = points.shape[0]
@@ -97,6 +102,7 @@ def vandermonde(ring: Ring, points: jnp.ndarray, K: int) -> jnp.ndarray:
     return jnp.moveaxis(cols, 0, 1)  # (n, K, D)
 
 
+@partial(jax.jit, static_argnums=0)
 def eval_poly_horner(ring: Ring, coeffs: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     """Evaluate sum_k coeffs[k] x^k; coeffs (K, ..., D), x (D,) -> (..., D)."""
     K = coeffs.shape[0]
@@ -109,6 +115,7 @@ def eval_poly_horner(ring: Ring, coeffs: jnp.ndarray, x: jnp.ndarray) -> jnp.nda
     return out
 
 
+@partial(jax.jit, static_argnums=0)
 def lagrange_coeff_matrix(ring: Ring, points: jnp.ndarray) -> jnp.ndarray:
     """Traceable M[k, i]: coefficients of Lagrange basis polys. (n, n, D).
 
@@ -150,6 +157,7 @@ def lagrange_coeff_matrix(ring: Ring, points: jnp.ndarray) -> jnp.ndarray:
     return jnp.moveaxis(basis, 0, 1)  # (k, i, D)
 
 
+@partial(jax.jit, static_argnums=0)
 def interpolate_coeffs(
     ring: Ring, points: jnp.ndarray, values: jnp.ndarray
 ) -> jnp.ndarray:

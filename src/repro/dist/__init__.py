@@ -27,7 +27,7 @@ fictions.  This package runs it for real:
     heartbeats and membership (``core.straggler.MembershipEvents``),
     dispatches per-worker ``encode_*_at`` shares (pipelined in
     contraction-axis chunks when they are large), re-dispatches the shares
-    of workers that die mid-request, and fires the LRU-cached any-R
+    of workers that die mid-request, and fires the any-R
     ``decode_op`` at the R-th response; plus :class:`LocalPool`, which
     spawns a local master + N worker OS processes in one call;
   * :mod:`repro.dist.launch` — the multi-host launcher

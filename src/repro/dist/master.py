@@ -8,7 +8,7 @@ jitted ``encode_*_at`` closures the elastic backend uses, ships each
 share to a live worker process (chunked along the contraction axis when
 ``stream_chunk_bytes`` says the share is big enough to pipeline — the
 worker accumulates partial products, so transfer and compute overlap),
-and fires the LRU-cached any-R ``decode_op`` the moment the R-th
+and fires the any-R ``decode_op`` the moment the R-th
 response lands — through :func:`repro.cdmm.elastic.decode_responses`,
 the exact decode tail of the in-process elastic master, so the two paths
 are bit-identical by construction.

@@ -1,10 +1,9 @@
 """One documented accessor for every ``REPRO_*`` environment knob.
 
 Env-var reads used to be scattered: ``REPRO_CALIBRATION`` in
-``cdmm/calibrate.py``, ``REPRO_DEBUG_SOLVE`` in ``core/gcsa.py``,
-``REPRO_CONFORMANCE_INPROC`` in the conformance suite, the deprecated
-``REPRO_POOL_WORKERS`` shim in ``dist/config.py``, and the tracing
-switch nowhere at all.  This module is the single registry: every knob
+``cdmm/calibrate.py``, ``REPRO_DEBUG_SOLVE`` in ``core/gcsa.py``, the
+deprecated ``REPRO_POOL_WORKERS`` shim in ``dist/config.py``, and the
+tracing switch nowhere at all.  This module is the single registry: every knob
 has a name, an env var, a typed default and a one-line doc, and every
 consumer goes through :func:`get` so ``python -m repro.settings`` (or
 :func:`describe`) always prints the true, complete list.
@@ -75,11 +74,6 @@ SETTINGS: Dict[str, Setting] = {
             "debug_solve", "REPRO_DEBUG_SOLVE", "bool", False,
             "run-time duplicate-live-set checks inside jitted decode "
             "paths via jax.debug.callback",
-        ),
-        Setting(
-            "conformance_inproc", "REPRO_CONFORMANCE_INPROC", "bool", False,
-            "run the conformance sweep fine-grained in-process instead of "
-            "the subprocess-sharded quarantine variant",
         ),
         Setting(
             "trace", "REPRO_TRACE", "bool", False,

@@ -1,19 +1,25 @@
 """Suite-wide fixtures.
 
-The one below works around a native crash in the pinned jaxlib: once a
-single CPU-client process has accumulated roughly 125 live compiled
-programs, the next XLA ``backend_compile`` segfaults (no Python
-traceback; faulthandler shows the main thread inside
-``jax/_src/compiler.py:backend_compile``).  The full suite compiles well
-past that across its ~20 modules, so whichever compile-heavy test file
-runs around the threshold took the whole session down — historically
-``test_conformance.py``'s sweeps (see the quarantine note there), but
-the crash site just moves when any one test is isolated.  Dropping every
-jit/pjit cache at module boundaries releases the finished modules'
-executables and keeps the live-program count bounded for the whole run,
-at the cost of re-tracing shared helpers in later modules.
+``_time_limit`` bounds every test: a test that runs past
+``TEST_TIME_LIMIT_S`` fails with a clear message, and faulthandler prints
+every thread's stack at the same moment (also when the main thread is stuck
+in native code, where the alarm's Python handler cannot run).  A hang then
+costs one failure, not the whole run.  xdist runs each test on its worker's
+main thread, so SIGALRM reaches it.
+
+No jit cache is cleared between modules.  Eager ring ops, decodes and the
+shard_map backend compile once per shape, so a worker's count of live
+programs stays bounded; a test that compiles on every call can bring back
+the pinned jaxlib's crash in ``backend_compile`` that once showed near
+~125 live programs in one process.
 """
+import faulthandler
+import signal
+import time
+
 import pytest
+
+TEST_TIME_LIMIT_S = 300
 
 
 def pytest_configure(config):
@@ -26,9 +32,35 @@ def pytest_configure(config):
     )
 
 
-@pytest.fixture(autouse=True, scope="module")
-def _bound_live_xla_programs():
-    yield
-    import jax
+@pytest.fixture(autouse=True)
+def _time_limit(request):
+    def expired(signum, frame):
+        pytest.fail(
+            f"{request.node.nodeid} ran past the {TEST_TIME_LIMIT_S} s "
+            "per-test limit (stacks of every thread on stderr)"
+        )
 
-    jax.clear_caches()
+    previous = signal.signal(signal.SIGALRM, expired)
+    signal.alarm(TEST_TIME_LIMIT_S)
+    faulthandler.dump_traceback_later(TEST_TIME_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        faulthandler.cancel_dump_traceback_later()
+        signal.signal(signal.SIGALRM, previous)
+
+
+@pytest.fixture
+def wait_until():
+    """``wait_until(cond, what)`` polls ``cond()`` until it holds, so a test
+    that needs another thread or process to reach a step waits for that
+    step itself, not for a sleep that a loaded machine can outrun."""
+
+    def wait(cond, what, timeout=60.0):
+        deadline = time.monotonic() + timeout
+        while not cond():
+            assert time.monotonic() < deadline, f"{what}: not after {timeout} s"
+            time.sleep(0.005)
+
+    return wait

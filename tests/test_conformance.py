@@ -183,82 +183,12 @@ def test_every_registered_family_is_covered():
     assert {"ep_secure", "ep_rmfe_secure"} <= set(registered_schemes())
 
 
-def _spawn_sweep(backend: str):
-    """Start the full (family x seed) sweep for one backend in a fresh
-    interpreter; returns the Popen handle."""
-    import subprocess
-    import sys
-
-    paths = [os.path.dirname(os.path.abspath(__file__))]
-    paths += [p for p in sys.path if p]
-    code = (
-        f"import sys; sys.path[:0] = {paths!r}\n"
-        "import test_conformance as tc\n"
-        "names = sorted(tc.registered_schemes())\n"
-        "for name in names:\n"
-        "    for seed in (0, 1):\n"
-        f"        tc.check_conformance(name, {backend!r}, seed)\n"
-        "print('SWEEP-OK', len(names))\n"
-    )
-    env = dict(os.environ)
-    env.setdefault(
-        "XLA_FLAGS", "--xla_force_host_platform_device_count=8"
-    )
-    return subprocess.Popen(
-        [sys.executable, "-c", code],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True, env=env,
-    )
-
-
-from repro import settings as repro_settings  # noqa: E402
-
-if repro_settings.get_bool("conformance_inproc"):
-
-    @pytest.mark.parametrize("backend", _backend_params())
-    @pytest.mark.parametrize("name", sorted(registered_schemes()))
-    @pytest.mark.parametrize("seed", [0, 1])
-    def test_conformance_sweep(name, backend, seed):
-        """Deterministic fallback sweep, fine-grained in-process variant
-        (REPRO_CONFORMANCE_INPROC=1 — for running this file standalone
-        with per-case reporting)."""
-        check_conformance(name, backend, seed)
-
-else:
-
-    def test_conformance_sweep():
-        """Deterministic fallback sweep: always runs, hypothesis or not.
-
-        Quarantined into one subprocess per backend (all three running
-        concurrently, so wall time stays near the single-backend cost):
-        running this sweep in-process as part of the full suite
-        deterministically crashes XLA's native ``backend_compile``
-        (SIGSEGV, no Python traceback) once the parent process has
-        accumulated ~125 compiled programs from the earlier test files —
-        a CPU-client teardown bug in the pinned jaxlib, present since the
-        repro.dist PR, and not reproducible when this file runs
-        standalone.  Fresh interpreters keep the sweep's ~300
-        compilations out of the parent process's compilation count while
-        preserving the exact same coverage (set
-        REPRO_CONFORMANCE_INPROC=1 for the fine-grained in-process
-        variant).
-        """
-        backends = [b for b in BACKENDS if b != "shard_map" or NDEV >= 8]
-        procs = {b: _spawn_sweep(b) for b in backends}
-        failures = []
-        for b, proc in procs.items():
-            try:
-                out, err = proc.communicate(timeout=1200)
-            except Exception:
-                proc.kill()
-                out, err = proc.communicate()
-                failures.append(f"{b}: timed out\n{err[-2000:]}")
-                continue
-            if proc.returncode != 0 or "SWEEP-OK" not in out:
-                failures.append(
-                    f"{b}: rc={proc.returncode}\n{out[-1000:]}\n"
-                    f"{err[-4000:]}"
-                )
-        assert not failures, "\n---\n".join(failures)
+@pytest.mark.parametrize("backend", _backend_params())
+@pytest.mark.parametrize("name", sorted(registered_schemes()))
+@pytest.mark.parametrize("seed", [0, 1])
+def test_conformance_sweep(name, backend, seed):
+    """Deterministic sweep: always runs, hypothesis or not."""
+    check_conformance(name, backend, seed)
 
 
 @pytest.mark.parametrize(
@@ -301,7 +231,7 @@ def pool_backend():
     from repro.dist import LocalPool, PoolBackend
 
     with LocalPool(workers=4) as p:
-        yield PoolBackend(p)
+        yield PoolBackend(p, timeout=120)
 
 
 @pytest.mark.parametrize("name", sorted(registered_schemes()))

@@ -157,6 +157,10 @@ def pool():
         yield p
 
 
+def _tasks_sent(master) -> int:
+    return sum(r["tasks_sent"] for r in master.worker_reports().values())
+
+
 def _problem(scheme, rng):
     if scheme.batch > 1:
         A = scheme.base.random(rng, (scheme.batch, 8, 8))
@@ -223,7 +227,7 @@ def test_pool_matches_local_and_multiplexes_shares(pool):
     assert scheme.N > POOL_WORKERS
     rng = np.random.default_rng(0)
     A, B = _problem(scheme, rng)
-    be = PoolBackend(pool)
+    be = PoolBackend(pool, timeout=120)
     C = coded_matmul(A, B, scheme, backend=be)
     C_local = coded_matmul(A, B, scheme, backend="local")
     np.testing.assert_array_equal(np.asarray(C), np.asarray(C_local))
@@ -241,7 +245,7 @@ def test_pool_respects_mask_subset(pool):
     live = rng.choice(scheme.N, size=scheme.R, replace=False)
     mask = np.zeros(scheme.N, dtype=bool)
     mask[live] = True
-    be = PoolBackend(pool)
+    be = PoolBackend(pool, timeout=120)
     C = coded_matmul(A, B, scheme, backend=be, mask=jnp.asarray(mask))
     C_local = coded_matmul(A, B, scheme, backend="local")
     np.testing.assert_array_equal(np.asarray(C), np.asarray(C_local))
@@ -253,7 +257,7 @@ def test_pool_secure_scheme_keyed_bit_identical(pool):
     scheme = plan(spec).instantiate()
     rng = np.random.default_rng(2)
     A, B = _problem(scheme, rng)
-    be = PoolBackend(pool)
+    be = PoolBackend(pool, timeout=120)
     C = coded_matmul(A, B, scheme, backend=be, key=KEY)
     C_local = coded_matmul(A, B, scheme, backend="local", key=KEY)
     np.testing.assert_array_equal(np.asarray(C), np.asarray(C_local))
@@ -276,7 +280,7 @@ def test_scheduler_concurrent_requests_and_plan_cache(pool):
         assert sched.stats.plan_cache_hits == 5
 
 
-def test_scheduler_admission_control(pool):
+def test_scheduler_admission_control(pool, wait_until):
     spec = ProblemSpec(t=8, r=8, s=8, n=1, ring=Z32, N=4)
     scheme = plan(spec).instantiate()
     rng = np.random.default_rng(4)
@@ -288,7 +292,7 @@ def test_scheduler_admission_control(pool):
     try:
         with PoolScheduler(pool.master, max_queue=1, max_inflight=1) as sched:
             f1 = sched.submit(A, B, scheme=scheme)
-            time.sleep(0.05)  # let the dispatcher pick f1 up
+            wait_until(f1.running, "the dispatcher picking f1 up")
             f2 = sched.submit(A, B, scheme=scheme)
             with pytest.raises(SchedulerSaturated):
                 sched.submit(A, B, scheme=scheme)
@@ -314,7 +318,7 @@ def test_submit_arg_validation(pool):
 # --------------------------------------------------------------------------
 
 
-def test_sigkill_n_minus_r_workers_mid_request_still_decodes():
+def test_sigkill_n_minus_r_workers_mid_request_still_decodes(wait_until):
     workers = 5
     spec = ProblemSpec(t=8, r=8, s=8, n=1, ring=Z32, N=workers,
                        straggler_budget=2)
@@ -328,7 +332,7 @@ def test_sigkill_n_minus_r_workers_mid_request_still_decodes():
     A, B = _problem(scheme, rng)
     oracle = np.asarray(coded_matmul(A, B, scheme, backend="local"))
     with LocalPool(workers=workers) as fresh:
-        be = PoolBackend(fresh)
+        be = PoolBackend(fresh, timeout=120)
         # warm round: workers jit their ring matmul before the race
         np.testing.assert_array_equal(
             np.asarray(coded_matmul(A, B, scheme, backend=be)), oracle
@@ -343,9 +347,11 @@ def test_sigkill_n_minus_r_workers_mid_request_still_decodes():
             except Exception as e:  # pragma: no cover - surfaced in assert
                 result["err"] = e
 
+        sent = _tasks_sent(fresh.master)
         t = threading.Thread(target=_request)
         t.start()
-        time.sleep(0.1)  # tasks dispatched; every worker is parked
+        wait_until(lambda: _tasks_sent(fresh.master) >= sent + scheme.N,
+                   "every share dispatched")  # and every worker parked
         assert len(fresh.kill(kill)) == kill
         t.join(timeout=120)
         assert not t.is_alive(), "request hung after SIGKILL"
@@ -398,6 +404,10 @@ def test_heartbeat_timeout_detects_stalled_worker():
         oracle = np.asarray(coded_matmul(A, B, scheme, backend="local"))
         # warm round so the stall is the only slow thing left
         fresh.execute(scheme, A, B, timeout=120)
+        # blind round-robin again: health-aware dispatch skips a worker
+        # that the warm round's compile or heartbeat jitter scored low, and
+        # a victim holding no share is never waited on, so never unmasked
+        fresh.master.health.reset_scores()
         victim = fresh.procs[0]
         os.kill(victim.pid, _signal.SIGSTOP)
         try:
@@ -415,7 +425,7 @@ def test_pool_trace_reflects_membership():
         scheme = plan(spec).instantiate()
         rng = np.random.default_rng(6)
         A, B = _problem(scheme, rng)
-        fresh.execute(scheme, A, B)
+        fresh.execute(scheme, A, B, timeout=120)
         tr = fresh.master.trace()
         assert tr.N == 2
         assert tr.mask().all()  # both responded

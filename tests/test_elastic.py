@@ -28,6 +28,7 @@ from repro.cdmm import (
     get_scheme,
     plan,
 )
+from repro.dist.chips import compile_counter
 from repro.runtime.elastic import replan_batch
 
 Z32 = make_ring(2, 32, ())
@@ -168,15 +169,16 @@ def test_decode_subset_cache_across_two_live_sets():
     assert set1 != set2, "the two masks must exercise different subsets"
     np.testing.assert_array_equal(np.asarray(C1), expect)
     np.testing.assert_array_equal(np.asarray(C2), expect)
-    cache = scheme.__dict__["_decode_ops"]
-    assert set(cache) >= {set1, set2}
-    # replaying a seen live set hits the cached operator (same object, no
-    # new entry) and still decodes exactly
-    size = len(cache)
-    op_before = cache[set1]
-    C1b = eb(scheme, A, B, mask=jnp.asarray(m1))
-    assert len(cache) == size and cache[set1] is op_before
-    np.testing.assert_array_equal(np.asarray(C1b), expect)
+    # the live set is data to one jitted decode: a third live set, and a
+    # replay of the first, compile nothing and still decode exactly
+    compiles = compile_counter()
+    m3 = np.ones(scheme.N, bool)
+    m3[[3, 4]] = False
+    for mask in (m3, m1):
+        C = eb(scheme, A, B, mask=jnp.asarray(mask))
+        np.testing.assert_array_equal(np.asarray(C), expect)
+    assert eb.last_stats.live_idx == set1
+    assert compiles() == 0
 
 
 def test_decode_op_validates_subset():

@@ -12,6 +12,8 @@ from repro.core.galois import (
     is_irreducible_gfp,
     _poly_mulmod,
 )
+from repro.core.polyops import lagrange_coeff_matrix, vandermonde
+from repro.dist.chips import compile_counter
 
 RINGS = [
     make_ring(2, 32, ()),          # Z_{2^32}
@@ -194,3 +196,28 @@ def test_jit_traceable(rng):
 
     a = ring.random_units(rng, (3,))
     assert np.array_equal(np.asarray(g(a)), np.asarray(ring.inv(a)))
+
+
+@pytest.mark.parametrize(
+    "ring",
+    [make_ring(2, 32, ()), make_ring(2, 16, (3,)), make_ring(3, 2, (2,))],
+    ids=["Z_2^32", "GR(2^16,3)", "GR(9,2)"],
+)
+def test_repeated_eager_ops_compile_once(ring):
+    """Eager ring ops and polyops helpers compile once per (ring, shapes):
+    a second round on the same ring and shapes compiles nothing."""
+    rng = np.random.default_rng(5)
+    a, b = ring.random_units(rng, (4, 3)), ring.random(rng, (4, 3))
+    A, B = ring.random(rng, (3, 4)), ring.random(rng, (4, 2))
+    pts = jnp.asarray(ring.exceptional_points(2))
+
+    def one_round():
+        jax.block_until_ready((
+            ring.mul(a, b), ring.matmul(A, B), ring.inv(a),
+            lagrange_coeff_matrix(ring, pts), vandermonde(ring, pts, 3),
+        ))
+
+    one_round()
+    compiles = compile_counter()
+    one_round()
+    assert compiles() == 0

@@ -169,7 +169,7 @@ def test_gr_solve_singular_raises(rng):
 def test_decode_duplicate_live_set_raises(rng):
     """Duplicate worker indices make the decode system singular; both CSA
     and general-GCSA decode must raise — including under jit, where the
-    live set is a concrete closure constant (the decode_op seam)."""
+    live set is a concrete closure constant."""
     ring = make_ring(2, 16, (4,))
     csa = CSACode(ring, L=3, N=8)
     As = ring.random(rng, (3, 3, 3))

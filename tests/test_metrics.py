@@ -229,7 +229,7 @@ def test_settings_cli_lists_telemetry_knobs():
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run(
         [sys.executable, "-m", "repro.settings"],
-        capture_output=True, text=True, env=env, check=True,
+        capture_output=True, text=True, env=env, check=True, timeout=120,
     ).stdout
     for knob in ("REPRO_OBS_HTTP_PORT", "REPRO_HEDGE_FACTOR",
                  "REPRO_HEALTH_EWMA", "REPRO_OBS_RETENTION"):
@@ -338,10 +338,10 @@ def _warm_and_seed(master, scheme, A, B):
     re-seed the hedge window with steady-state samples (>= 8 needed)."""
     master.hedge_factor = 0.0
     for _ in range(3):
-        master.execute(scheme, A, B)
+        master.execute(scheme, A, B, timeout=120)
     master.health.clear_window()
     for _ in range(2):
-        master.execute(scheme, A, B)
+        master.execute(scheme, A, B, timeout=120)
 
 
 @pytest.fixture(scope="module")
@@ -371,7 +371,7 @@ def test_aggressive_hedging_discards_duplicates_idempotently(hedge_pool):
             for wid in master.live_workers():
                 master.task_delay_ms[wid] = 150.0
             master.hedge_factor = 1.05
-            C, st = master.execute(scheme, A, B)
+            C, st = master.execute(scheme, A, B, timeout=120)
             master.hedge_factor = 0.0
             master.task_delay_ms.clear()
             np.testing.assert_array_equal(np.asarray(C), oracle)
@@ -384,7 +384,7 @@ def test_aggressive_hedging_discards_duplicates_idempotently(hedge_pool):
     assert after["pool_hedged"] >= before["pool_hedged"] + 3
     assert after["pool_hedge_wasted"] >= 0
     # the pool is not poisoned: a clean request still decodes exactly
-    C, st = master.execute(scheme, A, B)
+    C, st = master.execute(scheme, A, B, timeout=120)
     np.testing.assert_array_equal(np.asarray(C), oracle)
     assert st.hedged == 0
     master.health.clear_window()
@@ -412,7 +412,7 @@ def test_hedged_secure_decode_bit_identical_under_fixed_key(hedge_pool):
     A = Z32.random(rng, (8, 8))
     B = Z32.random(rng, (8, 8))
     key = jax.random.PRNGKey(7)
-    be = PoolBackend(pool)
+    be = PoolBackend(pool, timeout=120)
     C_local = np.asarray(coded_matmul(A, B, scheme, backend="local", key=key))
     # unhedged pool run (also jit-warms the 8x8 keyed path), then the
     # hedged run with every worker parked past the deadline
@@ -441,7 +441,7 @@ def test_hedged_secure_decode_bit_identical_under_fixed_key(hedge_pool):
 
 
 @pytest.mark.slow
-def test_sigkilled_then_hedged_worker_still_satisfies_oracle():
+def test_sigkilled_then_hedged_worker_still_satisfies_oracle(wait_until):
     """A worker is SIGKILLed after its share was already speculatively
     hedged: the replica (or the death re-dispatch, whichever lands
     first) must complete the zero-slack decode bit-identically."""
@@ -458,15 +458,17 @@ def test_sigkilled_then_hedged_worker_still_satisfies_oracle():
 
         def _request():
             try:
-                C, result["stats"] = master.execute(scheme, A, B)
+                C, result["stats"] = master.execute(scheme, A, B, timeout=120)
                 result["C"] = np.asarray(C)
             except Exception as e:  # surfaced below
                 result["err"] = e
 
         master.hedge_factor = 2.0
+        hedged0 = master.stats()["pool_hedged"]
         t = threading.Thread(target=_request)
         t.start()
-        time.sleep(0.15)  # shares dispatched; overdue shares hedged
+        wait_until(lambda: master.stats()["pool_hedged"] > hedged0,
+                   "an overdue share hedged")
         assert len(fresh.kill(1)) == 1
         t.join(timeout=120)
         master.hedge_factor = 0.0

@@ -353,7 +353,7 @@ def test_pool_trace_covers_every_stage_and_responder(pool):
     ring, scheme, A, B = _scheme_and_problem()
     obs.set_enabled(True)
     ctx = TraceContext.new("test")
-    C, stats = pool.master.execute(scheme, A, B, trace=ctx)
+    C, stats = pool.master.execute(scheme, A, B, trace=ctx, timeout=120)
     np.testing.assert_array_equal(
         np.asarray(C), np.asarray(ring.matmul(A, B))
     )
@@ -380,10 +380,10 @@ def test_pool_trace_covers_every_stage_and_responder(pool):
 def test_pool_trace_bit_identical_on_vs_off(pool):
     ring, scheme, A, B = _scheme_and_problem(seed=3)
     obs.set_enabled(False)
-    C_off, _ = pool.master.execute(scheme, A, B)
+    C_off, _ = pool.master.execute(scheme, A, B, timeout=120)
     obs.set_enabled(True)
     C_on, _ = pool.master.execute(
-        scheme, A, B, trace=TraceContext.new("test")
+        scheme, A, B, trace=TraceContext.new("test"), timeout=120
     )
     np.testing.assert_array_equal(np.asarray(C_off), np.asarray(C_on))
     assert len(obs.tracer()) > 0  # tracing actually recorded
@@ -403,7 +403,7 @@ def test_pool_trace_v0_peer_interop_synthesizes_spans(pool):
         ring, scheme, A, B = _scheme_and_problem(seed=5)
         obs.set_enabled(True)
         ctx = TraceContext.new("test")
-        C, _ = master.execute(scheme, A, B, trace=ctx)
+        C, _ = master.execute(scheme, A, B, trace=ctx, timeout=120)
         np.testing.assert_array_equal(
             np.asarray(C), np.asarray(ring.matmul(A, B))
         )
@@ -419,7 +419,7 @@ def test_pool_trace_v0_peer_interop_synthesizes_spans(pool):
 
 
 @pool_tests
-def test_pool_trace_sigkill_leaves_dead_worker_footprint(pool):
+def test_pool_trace_sigkill_leaves_dead_worker_footprint(pool, wait_until):
     # a kill-resilient scheme on a dedicated pool: SIGKILL one worker
     # mid-request; the merged timeline must show the send to the dead
     # worker AND the re-dispatched replacement share AND >= R compute
@@ -430,8 +430,11 @@ def test_pool_trace_sigkill_leaves_dead_worker_footprint(pool):
     oracle = np.asarray(ring.matmul(A, B))
     with LocalPool(workers=scheme.N) as victim_pool:
         master = victim_pool.master
-        warm, _ = master.execute(scheme, A, B)  # jit before the race
+        warm, _ = master.execute(scheme, A, B, timeout=120)  # jit before the race
         np.testing.assert_array_equal(np.asarray(warm), oracle)
+        # blind round-robin, so the worker killed below holds a share
+        # however the warm round scored it
+        master.health.reset_scores()
         for wid in master.live_workers():
             master.task_delay_ms[wid] = 300.0
         obs.set_enabled(True)
@@ -440,12 +443,19 @@ def test_pool_trace_sigkill_leaves_dead_worker_footprint(pool):
 
         def _request():
             result["C"], result["stats"] = master.execute(
-                scheme, A, B, trace=ctx
+                scheme, A, B, trace=ctx, timeout=120
             )
 
+        def sent():
+            return sum(r["tasks_sent"]
+                       for r in master.worker_reports().values())
+
+        sent0 = sent()
         t = threading.Thread(target=_request)
         t.start()
-        time.sleep(0.075)  # tasks dispatched, workers parked
+        # every share dispatched and every worker parked, so the kill
+        # strands a share that has to be re-dispatched
+        wait_until(lambda: sent() >= sent0 + scheme.N, "every share dispatched")
         killed = victim_pool.kill(1)
         assert killed
         t.join(timeout=120)
