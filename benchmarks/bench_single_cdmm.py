@@ -28,7 +28,7 @@ from repro.cdmm.api import (
     PlainCDMMAdapter,
     ProblemSpec,
 )
-from repro.cdmm.elastic import worker_closures
+from repro.cdmm.elastic import share_compute, share_encoders
 from repro.core import make_ring
 
 from .common import emit, timeit
@@ -96,10 +96,11 @@ def _bench_elastic_stages(N, schemes, size, spec, A, B, iters):
     for name, sch in schemes.items():
         m = sch.ring.D
         c = sch.costs(spec)
-        encode_at, compute = worker_closures(sch)
+        (enc_a, enc_b), compute = share_encoders(sch), share_compute(sch)
 
-        def enc_all(a, b, _enc=encode_at, _n=N):
-            return [_enc(a, b, jnp.int32(i)) for i in range(_n)]
+        def enc_all(a, b, _ea=enc_a, _eb=enc_b, _n=N):
+            return [(_ea(a, jnp.int32(i)), _eb(b, jnp.int32(i)))
+                    for i in range(_n)]
 
         FA = sch.encode_a(A)
         GB = sch.encode_b(B)
@@ -198,10 +199,11 @@ def _bench_pool_stages(pool, N, schemes, size, spec, A, B, iters):
     for name, sch in schemes.items():
         m = sch.ring.D
         c = sch.costs(spec)
-        encode_at, compute = worker_closures(sch)
+        (enc_a, enc_b), compute = share_encoders(sch), share_compute(sch)
 
-        def enc_all(a, b, _enc=encode_at, _n=N):
-            return [_enc(a, b, jnp.int32(i)) for i in range(_n)]
+        def enc_all(a, b, _ea=enc_a, _eb=enc_b, _n=N):
+            return [(_ea(a, jnp.int32(i)), _eb(b, jnp.int32(i)))
+                    for i in range(_n)]
 
         FA = sch.encode_a(A)
         GB = sch.encode_b(B)

@@ -56,7 +56,8 @@ __all__ = [
     "ElasticStream",
     "NotEnoughResponders",
     "decode_responses",
-    "worker_closures",
+    "share_compute",
+    "share_encoders",
 ]
 
 
@@ -107,31 +108,37 @@ def decode_responses(
     return scheme.decode_op(idx)(jnp.stack([got[i] for i in idx]))
 
 
-def worker_closures(
-    scheme: CdmmScheme, keyed: bool = False, use_kernel: bool = False
-):
-    """Jitted (encode_at, compute) closures, cached per scheme instance so
-    repeated elastic calls never re-trace.  The worker id is a traced scalar
-    (one compilation covers all N workers); worker shares are donated to the
-    compute (single-use buffers; donation is a warn-only no-op on CPU).
-    ``keyed`` selects the keyed-encode variant (the masked-randomness seam:
-    the PRNG key is a traced argument so rekeying never re-compiles).
-    ``use_kernel=True`` routes each worker's block product through the
-    tuned Pallas kernel (False: XLA) — every registered scheme's
-    ``worker_compute`` is exactly the ring matmul of its two shares, so the
-    substitution is scheme-agnostic and exact."""
+def share_encoders(scheme: CdmmScheme, keyed: bool = False):
+    """Jitted ``(encode_a_at, encode_b_at)`` of one share each, cached per
+    scheme instance so repeated calls never re-trace.  The share index is a
+    traced scalar (one compilation covers all N shares).  Apart, a caller
+    can encode A alone and take B's share from elsewhere (the pool
+    master's weight-share cache).  ``keyed`` selects the keyed variants
+    (the masked-randomness seam: the PRNG key is a traced last argument so
+    rekeying never re-compiles); both halves take the same key."""
     ops = scheme.__dict__.setdefault("_elastic_ops", {})
-    ename = "encode_keyed" if keyed else "encode"
-    if ename not in ops:
+    suffix = "_keyed" if keyed else ""
+    if "encode_a" + suffix not in ops:
         if keyed:
-            ops[ename] = jax.jit(lambda a, b, i, k: (
-                scheme.encode_a_at(a, i, key=k),
-                scheme.encode_b_at(b, i, key=k),
-            ))
+            ops["encode_a_keyed"] = jax.jit(
+                lambda a, i, k: scheme.encode_a_at(a, i, key=k))
+            ops["encode_b_keyed"] = jax.jit(
+                lambda b, i, k: scheme.encode_b_at(b, i, key=k))
         else:
-            ops[ename] = jax.jit(lambda a, b, i: (
-                scheme.encode_a_at(a, i), scheme.encode_b_at(b, i)
-            ))
+            ops["encode_a"] = jax.jit(lambda a, i: scheme.encode_a_at(a, i))
+            ops["encode_b"] = jax.jit(lambda b, i: scheme.encode_b_at(b, i))
+    return ops["encode_a" + suffix], ops["encode_b" + suffix]
+
+
+def share_compute(scheme: CdmmScheme, use_kernel: bool = False):
+    """Jitted ``compute(fa, gb)`` of one worker's share product, cached per
+    scheme instance.  Worker shares are donated to the compute (single-use
+    buffers; donation is a warn-only no-op on CPU).  ``use_kernel=True``
+    routes the block product through the tuned Pallas kernel (False: XLA)
+    — every registered scheme's ``worker_compute`` is exactly the ring
+    matmul of its two shares, so the substitution is scheme-agnostic and
+    exact."""
+    ops = scheme.__dict__.setdefault("_elastic_ops", {})
     cname = "compute_kernel" if use_kernel else "compute"
     if cname not in ops:
         if use_kernel:
@@ -144,7 +151,7 @@ def worker_closures(
             body,
             donate_argnums=() if jax.default_backend() == "cpu" else (0, 1),
         )
-    return ops[ename], ops[cname]
+    return ops[cname]
 
 
 class ElasticBackend:
@@ -170,7 +177,7 @@ class ElasticBackend:
         self.trace = trace
         self.max_threads = max_threads
         self.simulate_ms_scale = simulate_ms_scale
-        # True: workers use the Pallas kernel (see worker_closures)
+        # True: workers use the Pallas kernel (see share_compute)
         self.use_kernel = use_kernel
         self.last_stats: Optional[ElasticStats] = None
         self._pool: Optional[ThreadPoolExecutor] = None
@@ -289,9 +296,8 @@ class ElasticBackend:
         dispatch = [i for i in np.argsort(trace.join_ms, kind="stable")
                     if trace.join_ms[i] <= t_R]
 
-        encode_at, compute = worker_closures(
-            scheme, keyed=key is not None, use_kernel=self.use_kernel
-        )
+        encode_a, encode_b = share_encoders(scheme, keyed=key is not None)
+        compute = share_compute(scheme, use_kernel=self.use_kernel)
 
         from repro.obs import trace as obs
 
@@ -324,10 +330,8 @@ class ElasticBackend:
         # compute of workers < k (the master thread never blocks here)
         for i in dispatch:
             t_e = obs.now()
-            if key is None:
-                fa, gb = encode_at(A, B, jnp.int32(i))
-            else:
-                fa, gb = encode_at(A, B, jnp.int32(i), key)
+            args = (jnp.int32(i),) if key is None else (jnp.int32(i), key)
+            fa, gb = encode_a(A, *args), encode_b(B, *args)
             tracer.add(ctx, "encode", "elastic", t_e, obs.now(),
                        share=int(i))
             pool.submit(worker_task, int(i), fa, gb)

@@ -3,8 +3,9 @@
 :class:`Master` listens on a socket, accepts worker registrations (the
 ``hello`` capability handshake, which now negotiates a wire codec per
 connection — see :mod:`repro.dist.protocol`), and executes coded matmuls
-against the pool: the master encodes per-worker shares with the same
-jitted ``encode_*_at`` closures the elastic backend uses, ships each
+against the pool: the master encodes per-worker shares with the jitted
+``encode_*_at`` closures of :func:`repro.cdmm.elastic.share_encoders`
+(B's shares come from a cache while the same weight comes back), ships each
 share to a live worker process (chunked along the contraction axis when
 ``stream_chunk_bytes`` says the share is big enough to pipeline — the
 worker accumulates partial products, so transfer and compute overlap),
@@ -53,13 +54,14 @@ import subprocess
 import tempfile
 import threading
 import time
+import weakref
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
 from repro import settings
-from repro.cdmm.elastic import NotEnoughResponders, decode_responses, worker_closures
+from repro.cdmm.elastic import NotEnoughResponders, decode_responses, share_encoders
 from repro.core.straggler import MembershipEvents
 from repro.obs import http as obs_http
 from repro.obs import trace as obs
@@ -168,8 +170,118 @@ class _Request:
         self.codecs: set = set()
 
 
+class _Weight:
+    """One cached B operand: an owned read-only copy and the B shares
+    encoded from it so far (share index -> read-only host array)."""
+
+    def __init__(self, operand: np.ndarray):
+        self.operand = operand
+        self.shares: Dict[int, np.ndarray] = {}
+
+
+class _WeightCache:
+    """The B operands a pool multiplied by lately, with their encoded
+    shares, per scheme instance.
+
+    A served layer multiplies every request by one weight, so codeword
+    after codeword stacks the same B and encodes the same N B shares.
+    That holds while the pool serves one weight per scheme instance; the
+    serving engine keeps one scheme per ``ProblemSpec``, so layers of one
+    shape that share a pool (a model's stacked projections, its routed
+    experts) mix weights in one scheme and miss.  ``lookup`` hits only
+    when a stored operand has the same shape, dtype and elements: a caller
+    may change its weight in place between calls, and that misses.  A
+    strided sample of the elements is compared first, so a different
+    weight misses without a full pass.  Each scheme keeps its
+    ``PER_SCHEME`` most recently used operands: two, a full batch and a
+    padded one of one weight.  A missed operand is stored once its first
+    share is encoded, so a codeword that fails before that leaves nothing
+    behind.  Shares are encoded from the owned copy, never from the
+    caller's array, and stored read-only, because requests in flight keep
+    references to them for redispatch and hedging.  Lookups and inserts
+    take one lock; two concurrent misses may both encode, and the first
+    stored entry is kept.
+    """
+
+    PER_SCHEME = 2
+    SAMPLE = 4096  # elements compared before the full comparison
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._by_scheme: "weakref.WeakKeyDictionary" = (
+            weakref.WeakKeyDictionary()
+        )
+
+    @classmethod
+    def _match(cls, entries, B: np.ndarray) -> Optional[_Weight]:
+        for w in entries:
+            a = w.operand
+            if a.shape != B.shape or a.dtype != B.dtype:
+                continue
+            step = max(1, a.size // cls.SAMPLE)
+            if (np.array_equal(a.reshape(-1)[::step], B.reshape(-1)[::step])
+                    and np.array_equal(a, B)):
+                return w
+        return None
+
+    def lookup(self, scheme, B) -> Tuple[_Weight, bool]:
+        """The stored entry equal to ``B``, made the scheme's most recent
+        (a hit); else a new entry holding a copy of ``B``, stored by
+        :meth:`share` once it holds a share."""
+        B = np.asarray(B)
+        with self._lock:
+            seen = list(self._by_scheme.get(scheme, ()))
+        # compared outside the lock: stored operands never change
+        w = self._match(seen, B)
+        if w is None:
+            operand = np.array(B, copy=True)
+            operand.setflags(write=False)
+            return _Weight(operand), False
+        with self._lock:
+            entries = self._by_scheme.get(scheme, [])
+            if w in entries:
+                entries.remove(w)
+                entries.append(w)
+        return w, True
+
+    def share(self, scheme, w: _Weight, i: int,
+              encode) -> Tuple[np.ndarray, bool]:
+        """B share ``i`` of ``w``, encoded by ``encode(operand, i)`` when
+        missing; also whether it was there already.  Stores ``w`` for
+        ``scheme`` with its first share, unless a concurrent miss stored an
+        equal operand first."""
+        with self._lock:
+            gb = w.shares.get(i)
+        if gb is not None:
+            return gb, True
+        gb = np.asarray(encode(w.operand, i))
+        gb.setflags(write=False)
+        with self._lock:
+            gb = w.shares.setdefault(i, gb)
+            entries = self._by_scheme.setdefault(scheme, [])
+            if w not in entries and self._match(entries, w.operand) is None:
+                entries.append(w)
+                del entries[:-self.PER_SCHEME]
+        return gb, False
+
+    def clear(self) -> None:
+        with self._lock:
+            self._by_scheme.clear()
+
+
 class Master:
-    """Accept workers, track membership, execute coded matmuls on the pool."""
+    """Accept workers, track membership, execute coded matmuls on the pool.
+
+    The master keeps the encoded B shares of the last two B operands of
+    each scheme (see :class:`_WeightCache`): a codeword whose B equals one
+    of them, byte for byte, encodes only A.  A keyed (secure) codeword
+    neither reads nor fills it.  It pays while the pool multiplies by one
+    weight per scheme instance; weights that take turns in one scheme miss.
+    A miss pays a sampled comparison with each stored operand and one host
+    copy of B, and the cache holds up to two operands with their N shares
+    per scheme: for a 4096x4096 weight over GR(2^32,3) at batch 2, about
+    0.4 GB of shares and 134 MB of operand per entry, until :meth:`close`.
+    """
 
     def __init__(
         self,
@@ -201,6 +313,7 @@ class Master:
         self.compression_level = cfg.compression_level
         self.stream_chunk_bytes = cfg.stream_chunk_bytes
         self.membership = MembershipEvents()
+        self._weights = _WeightCache()
         self._workers: Dict[int, _WorkerHandle] = {}
         self._requests: Dict[int, _Request] = {}
         self._lock = threading.Lock()
@@ -244,6 +357,10 @@ class Master:
             ("raw_bytes_in", "result payload bytes before the wire codec"),
             ("bytes_in", "bytes actually received on the wire"),
             ("heartbeats", "worker heartbeat messages received"),
+            ("weight_lookups", "unkeyed codewords whose B operand was "
+                               "looked up in the weight-share cache"),
+            ("weight_hits", "lookups that found the same B operand and "
+                            "reused its encoded shares"),
         ):
             self.metrics.counter(name, doc)
         self._wall_hist = self.metrics.histogram(
@@ -840,6 +957,23 @@ class Master:
             self._account(hedged=1)
         return next_due
 
+    def _encode_share(self, scheme, encoders, A, B, i: int, key,
+                      weight: Optional[_Weight]):
+        """Share ``i``'s host ``(fa, gb)``, and whether ``gb`` came from the
+        weight cache.  With ``weight`` (an unkeyed codeword's cache entry)
+        B's share is the cached one, encoded from the entry's own copy of
+        B on first use; without, both halves are encoded here."""
+        import jax.numpy as jnp
+
+        encode_a, encode_b = encoders
+        args = (jnp.int32(i),) if key is None else (jnp.int32(i), key)
+        fa = np.asarray(encode_a(A, *args))
+        if weight is None:
+            return fa, np.asarray(encode_b(B, *args)), False
+        gb, hit = self._weights.share(
+            scheme, weight, i, lambda b, j: encode_b(b, jnp.int32(j)))
+        return fa, gb, hit
+
     # -- protocol entry point ----------------------------------------------
 
     def execute(
@@ -859,7 +993,10 @@ class Master:
         share indices are never dispatched (the test seam for simulating
         straggler budgets deterministically).  ``key`` feeds the keyed
         encode of secure schemes — encode runs master-side, so workers
-        only ever see masked shares.  ``batch_fill`` is observability from
+        only ever see masked shares, and never touches the weight cache
+        (fresh masks per batch); without it, B's shares come from the
+        cache when B equals a recent operand of ``scheme`` byte for byte.
+        ``batch_fill`` is observability from
         a coalescing caller: how many of the scheme's batch slots carry
         real requests (the rest are padding), surfaced on PoolStats.
         ``trace`` carries an upstream :class:`repro.obs.TraceContext`
@@ -882,7 +1019,7 @@ class Master:
                 f"{scheme.name}: mask leaves {len(shares)} shares, "
                 f"decode needs R={R}"
             )
-        encode_at, _ = worker_closures(scheme, keyed=key is not None)
+        encoders = share_encoders(scheme, keyed=key is not None)
 
         with self._lock:
             rid = self._next_rid
@@ -895,17 +1032,18 @@ class Master:
         workers_used: List[int] = []
         ok = False
         try:
-            import jax.numpy as jnp
-
+            # an unkeyed codeword takes B's shares from the weight cache;
+            # the lookup is timed in the first share's encode span
+            weight = None
             for i in shares:
                 t_enc = obs.now()
-                if key is None:
-                    fa, gb = encode_at(A, B, jnp.int32(i))
-                else:
-                    fa, gb = encode_at(A, B, jnp.int32(i), key)
-                fa, gb = np.asarray(fa), np.asarray(gb)
+                if key is None and weight is None:
+                    weight, found = self._weights.lookup(scheme, B)
+                    self._account(weight_lookups=1, weight_hits=int(found))
+                fa, gb, hit = self._encode_share(scheme, encoders, A, B, i,
+                                                 key, weight)
                 tracer.add(trace, "encode", "pool", t_enc, obs.now(),
-                           share=i, scheme=scheme.name)
+                           share=i, scheme=scheme.name, hit=hit)
                 wid = self._send_task(req, scheme, i, fa, gb)
                 workers_used.append(wid)
             t_wait = obs.now()
@@ -974,13 +1112,10 @@ class Master:
                         )
                     if errors[i] < 2 and i not in got:
                         t_enc = obs.now()
-                        if key is None:
-                            fa, gb = encode_at(A, B, jnp.int32(i))
-                        else:
-                            fa, gb = encode_at(A, B, jnp.int32(i), key)
-                        fa, gb = np.asarray(fa), np.asarray(gb)
+                        fa, gb, hit = self._encode_share(
+                            scheme, encoders, A, B, i, key, weight)
                         tracer.add(trace, "encode", "pool", t_enc,
-                                   obs.now(), share=i, retry=True)
+                                   obs.now(), share=i, retry=True, hit=hit)
                         self._send_task(
                             req, scheme, i, fa, gb,
                             exclude=(bad_wid,), redispatch=True,
@@ -1041,6 +1176,7 @@ class Master:
         if self._closed:
             return
         self._closed = True
+        self._weights.clear()
         obs_http.unregister_source(self._obs_source)
         obs_http.unregister_trace_resolver(self._resolve_trace)
         with self._lock:
